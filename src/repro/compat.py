@@ -1,66 +1,36 @@
-"""jax version compatibility shims (0.4.x ↔ ≥0.5).
+"""The sharding API as this tree spells it (jax 0.9).
 
-The runtime targets the modern sharding API (``jax.shard_map`` with
-``axis_names``, ``jax.sharding.AxisType``, ``jax.lax.pvary``,
-``jax.set_mesh``); CI and some dev boxes carry jax 0.4.x where those live
-under different names (or do not exist and are semantically no-ops, like
-``pvary`` — the varying-mesh-axes checker it feeds was introduced later).
+One spelling of each call the rest of the tree uses:
 
-Everything version-dependent funnels through here so the rest of the tree
-imports one spelling. Each symbol degrades to the closest 0.4.x equivalent:
-
-- :func:`shard_map` — ``jax.shard_map(..., axis_names=manual)`` on new jax;
-  ``jax.experimental.shard_map.shard_map(..., auto=<complement>)`` (partial
-  manual) with ``check_rep=False`` on 0.4.x.
-- :func:`pvary` — identity on 0.4.x (no VMA checker to satisfy).
-- :func:`mesh_context` — ``jax.set_mesh`` on new jax; the ``Mesh`` object
-  itself (a context manager) on 0.4.x.
-- :func:`make_mesh` / :func:`mesh_from_devices` — drop the ``axis_types``
-  kwarg where it does not exist (0.4.x meshes are implicitly all-auto,
-  which is exactly what the Pier code requests).
+- :func:`make_mesh` / :func:`mesh_from_devices` — meshes whose axes are all
+  ``AxisType.Auto`` (the Pier steps make the group axes manual themselves,
+  inside :func:`shard_map`).
+- :func:`shard_map` — partial-manual ``jax.shard_map``: ``axis_names``
+  manual, the rest auto, with the varying-mesh-axes (VMA) check on.
+- :func:`mark_varying` — ``lax.pcast(..., to="varying")`` over only the
+  axes a value does not already vary over.
+- :func:`all_gather_invariant` — the all-gather whose result is typed
+  invariant over the gathered axis.
+- :func:`mesh_context` — ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Callable, Sequence, Set, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-# jax < 0.5 defaults jax_threefry_partitionable=False, under which random
-# bits generated into a sharded output differ from the same call eagerly /
-# replicated. Modern jax defaults True (sharding-invariant), and the code
-# here assumes it: e.g. the sim-vs-distributed equivalence relies on the
-# sharded init_state producing the same params as the eager init.
-try:
-    if not jax.config.jax_threefry_partitionable:
-        jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:
-    pass  # flag removed (always-on) in newer jax
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_PVARY = hasattr(jax.lax, "pvary")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-
-AXIS_TYPE_AUTO = jax.sharding.AxisType.Auto if HAS_AXIS_TYPE else None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with all-auto axis types where supported."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AXIS_TYPE_AUTO,) * len(shape))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with all-auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def mesh_from_devices(devices, axes: Sequence[str]) -> Mesh:
-    """``Mesh(devices, axes)`` with all-auto axis types where supported."""
-    if HAS_AXIS_TYPE:
-        return Mesh(devices, tuple(axes),
-                    axis_types=(AXIS_TYPE_AUTO,) * len(axes))
-    return Mesh(devices, tuple(axes))
+    """``Mesh(devices, axes)`` with all-auto axis types."""
+    return Mesh(devices, tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
 
 
 def shard_map(
@@ -72,29 +42,37 @@ def shard_map(
     axis_names: Set[str],
 ):
     """Partial-manual shard_map: ``axis_names`` manual, the rest auto."""
-    if HAS_NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(axis_names))
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(axis_names))
 
 
-def pvary(x, axis_names: Tuple[str, ...]):
-    """Mark ``x`` varying over manual axes (identity pre-VMA-checker jax)."""
-    if not axis_names:
-        return x
-    if HAS_PVARY:
-        return jax.lax.pvary(x, tuple(axis_names))
-    return x
+def mark_varying(x, axis_names: Tuple[str, ...]):
+    """Mark the pytree ``x`` varying over ``axis_names``.
+
+    ``pcast(to="varying")`` accepts only axes a value is invariant over, so
+    each leaf is cast over just the axes missing from its VMA type (a no-op
+    where it already varies over all of them).
+    """
+    def cast(leaf):
+        missing = tuple(a for a in axis_names
+                        if a not in jax.typeof(leaf).vma)
+        return jax.lax.pcast(leaf, missing, to="varying") if missing else leaf
+
+    return jax.tree.map(cast, x)
+
+
+def all_gather_invariant(x, axis_name: str):
+    """Stack ``x`` from every index of ``axis_name``, in axis-index order.
+
+    Every device ends with the same stack, so the result is typed invariant
+    over ``axis_name`` (``lax.all_gather`` types it varying). jax 0.9 keeps
+    this primitive under ``jax._src``.
+    """
+    from jax._src.lax.parallel import all_gather_invariant as _agi
+
+    return _agi(x, axis_name)
 
 
 def mesh_context(mesh: Mesh):
     """Context manager putting ``mesh`` in scope for sharding constraints."""
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh  # 0.4.x: Mesh is itself a context manager
-    return contextlib.nullcontext()
+    return jax.set_mesh(mesh)

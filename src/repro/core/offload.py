@@ -9,9 +9,9 @@ Each device offloads only its own shard (the paper's "avoid redundant data
 movement" note) — this falls out for free because we offload the sharded
 arrays as-is, preserving their sharding but switching the memory kind.
 
-On backends without pinned_host support (the CPU validation backend),
-offload degrades to a no-op and ``supports_offload()`` reports False; the
-switch semantics (`TrainConfig.offload_outer_state`) are identical.
+A device without a ``pinned_host`` memory cannot offload: :func:`to_host`
+then raises rather than leave the state in HBM while the run believes it
+was moved.
 """
 
 from __future__ import annotations
@@ -24,12 +24,8 @@ import jax
 
 @functools.cache
 def supports_offload() -> bool:
-    try:
-        dev = jax.devices()[0]
-        kinds = getattr(dev, "addressable_memories", lambda: [])()
-        return any(m.kind == "pinned_host" for m in kinds)
-    except Exception:
-        return False
+    dev = jax.devices()[0]
+    return any(m.kind == "pinned_host" for m in dev.addressable_memories())
 
 
 def _with_memory_kind(sharding, kind: str):
@@ -39,7 +35,11 @@ def _with_memory_kind(sharding, kind: str):
 def to_host(tree: Any) -> Any:
     """Move a pytree of arrays to pinned host memory (keeps sharding)."""
     if not supports_offload():
-        return tree
+        dev = jax.devices()[0]
+        raise RuntimeError(
+            f"outer-state offload needs a pinned_host memory on "
+            f"{dev.platform} ({dev.device_kind}); it has "
+            f"{[m.kind for m in dev.addressable_memories()]}")
 
     def move(x):
         if not isinstance(x, jax.Array):
@@ -51,9 +51,6 @@ def to_host(tree: Any) -> Any:
 
 def to_device(tree: Any) -> Any:
     """Bring an offloaded pytree back to device HBM."""
-    if not supports_offload():
-        return tree
-
     def move(x):
         if not isinstance(x, jax.Array):
             return x

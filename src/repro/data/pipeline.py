@@ -55,12 +55,13 @@ class DataPipeline:
         return out
 
     def _producer(self):
-        step = 0
+        step, batch = 0, None
         while not self._stop.is_set():
-            batch = self.make_batch(step)
+            if batch is None:
+                batch = self.make_batch(step)
             try:
                 self._q.put(batch, timeout=1.0)
-                step += 1
+                step, batch = step + 1, None
             except queue.Full:
                 continue
 
@@ -73,7 +74,10 @@ class DataPipeline:
         return self._shard(batch)
 
     def close(self):
+        """Stop the producer and wait for it: a thread still inside jax
+        when the interpreter exits aborts the process."""
         self._stop.set()
+        self._thread.join()
 
 
 def synthetic_pipeline(

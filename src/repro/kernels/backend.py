@@ -57,8 +57,12 @@ BACKEND_NAMES = ("tpu-mosaic", "gpu-triton", "interpret", "jnp-ref")
 #   ring_allreduce    — ``pltpu.make_async_remote_copy`` remote DMA
 # interpret column: every kernel body executes under the interpreter —
 # except the remote-DMA ring, whose semantics need a real multi-device
-# TPU ring (off-TPU the transport resolver picks ppermute/psum instead,
-# see kernels/ring_allreduce.resolve_transport).
+# TPU ring (the transport resolver picks the XLA collective instead, see
+# kernels/ring_allreduce.resolve_transport).
+# tpu-mosaic column: the remote-DMA ring does not compile for the chip
+# yet (Mosaic cannot prove its dynamic per-source row store aligned to the
+# int8 (4, 128) tile), so the wire exchange takes the XLA collective there
+# too until it does.
 KERNEL_CAPS: Mapping[str, Mapping[str, str]] = {
     "quantize": {
         "tpu-mosaic": COMPILED, "gpu-triton": COMPILED,
@@ -85,7 +89,7 @@ KERNEL_CAPS: Mapping[str, Mapping[str, str]] = {
         "interpret": INTERPRET, "jnp-ref": JNP,
     },
     "ring_allreduce": {
-        "tpu-mosaic": COMPILED, "gpu-triton": JNP,
+        "tpu-mosaic": JNP, "gpu-triton": JNP,
         "interpret": JNP, "jnp-ref": JNP,
     },
 }
@@ -120,12 +124,11 @@ def _detect_platform() -> str:
     """The jax platform — the only place kernels touch device state.
 
     Called lazily at the first kernel dispatch (never at import time).
-    The single monkeypatch seam for the fake-platform tests.
+    The single monkeypatch seam for the fake-platform tests. A backend
+    that fails to initialize raises here: guessing ``"cpu"`` would run
+    every kernel in interpret mode on a machine that has a chip.
     """
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
+    return jax.devices()[0].platform
 
 
 def default_backend_name() -> str:
@@ -196,6 +199,51 @@ def on_tpu() -> bool:
     if _is_tpu is None:
         _is_tpu = _detect_platform() == "tpu"
     return _is_tpu
+
+
+def out_struct(shape, dtype, *operands, invariant_over=()) -> jax.ShapeDtypeStruct:
+    """``out_shape`` entry of a ``pallas_call`` that may run in ``shard_map``.
+
+    jax's varying-mesh-axes (VMA) check needs every kernel output typed
+    with the manual axes it varies over: here the union of the operands'
+    axes, less ``invariant_over`` (axes the kernel itself makes every
+    device agree on, e.g. a ring all-gather's). Outside ``shard_map`` the
+    set is empty and ignored.
+    """
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                vma=vma - frozenset(invariant_over))
+
+
+def mosaic_call(fn, *args, interpret: bool = False):
+    """``fn(*args)`` where jax can lower the Mosaic kernel inside ``fn``.
+
+    jax lowers a compiled Pallas kernel only where every mesh axis is
+    manual (or on one device). Inside the Pier steps' partial-manual
+    ``shard_map`` the in-group axes are still auto (and under a mesh
+    context with no ``shard_map``, all of them are), so a nested
+    ``shard_map`` makes them manual, with the operands and results
+    replicated over them: over axes of size 1 that changes nothing; over
+    larger ones XLA gathers the operands and every device runs the whole
+    kernel (:func:`kernel_replicated_axes` names those axes). Interpret
+    mode lowers to plain HLO and needs none of this.
+    """
+    from jax.sharding import AxisType, PartitionSpec
+
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = tuple(n for n, t in zip(mesh.axis_names, mesh.axis_types)
+                 if t == AxisType.Auto)
+    if interpret or not auto:
+        return fn(*args)
+    return jax.shard_map(fn, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), axis_names=set(auto))(*args)
+
+
+def kernel_replicated_axes(mesh, manual) -> Tuple[str, ...]:
+    """Axes of size > 1 over which :func:`mosaic_call` replicates kernels
+    run inside a ``shard_map`` manual over ``manual``."""
+    return tuple(a for a in mesh.axis_names
+                 if a not in manual and mesh.shape[a] > 1)
 
 
 def kernel_lane(kernel: str) -> str:

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import resolve_kernel
+from repro.kernels.backend import mosaic_call, out_struct, resolve_kernel
 
 # jax < 0.5 names this TPUCompilerParams; it was renamed to CompilerParams.
 _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
@@ -67,9 +67,9 @@ def _decode_kernel(
     cl_ref,  # (B,) int32 context lengths
     # VMEM tiles
     q_ref,  # (1, 1, G, hd)
-    k_ref,  # (1, bs, 1, hd) — gathered pool block for this kv head
+    k_ref,  # (1, 1, bs, hd) — gathered pool block for this kv head
     v_ref,
-    *rest,  # [k_scale (1, bs, 1), v_scale (1, bs, 1)] when quantized, then
+    *rest,  # [k_scale (1, 1, bs, 1), v_scale (1, 1, bs, 1)] when quantized,
     # o_ref, m_scr (G, 1), l_scr (G, 1), acc_scr (G, hd)
     scale: float,
     block_size: int,
@@ -97,12 +97,12 @@ def _decode_kernel(
     def _compute():
         G = q_ref.shape[2]
         q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
             # elementwise-identical to quantize._dequant_kernel
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+            k = k * ks_ref[0, 0]  # (bs, hd) * (bs, 1)
+            v = v * vs_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (G, bs)
@@ -164,9 +164,10 @@ def paged_decode_attention(
         return paged_decode_attention_ref(
             q, k_pool, v_pool, block_tables, context_lens,
             k_scales, v_scales, window=window, softcap=softcap)
-    return _paged_decode_pallas(
-        q, k_pool, v_pool, block_tables, context_lens, k_scales, v_scales,
-        window=window, softcap=softcap, interpret=interpret)
+    return mosaic_call(functools.partial(
+        _paged_decode_pallas, window=window, softcap=softcap,
+        interpret=interpret), q, k_pool, v_pool, block_tables,
+        context_lens, k_scales, v_scales, interpret=interpret)
 
 
 @functools.partial(
@@ -194,23 +195,26 @@ def _paged_decode_pallas(q, k_pool, v_pool, block_tables, context_lens,
     def kv_map(b, h, j, bt, cl):
         # out-of-range logical blocks clamp to physical block 0; their
         # compute is skipped (j * bs >= cl) so the gathered data is unused
-        return (jnp.maximum(bt[b, j], 0), 0, h, 0)
+        return (jnp.maximum(bt[b, j], 0), h, 0, 0)
 
-    def scale_map(b, h, j, bt, cl):
-        return (jnp.maximum(bt[b, j], 0), 0, h)
-
+    # Head-major views of the pools: Mosaic blocks only the two minor dims
+    # whole (or in multiples of the (8, 128) tile), so one (bs, hd) tile
+    # per (pool block, kv head) needs Hkv ahead of bs. The transpose is an
+    # extra pass over the pool per call, the price of the cache's
+    # (N, bs, Hkv, hd) layout.
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), q_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
+        pl.BlockSpec((1, 1, bs, hd), kv_map),
+        pl.BlockSpec((1, 1, bs, hd), kv_map),
     ]
-    operands = [q4, k_pool, v_pool]
+    operands = [q4, jnp.swapaxes(k_pool, 1, 2), jnp.swapaxes(v_pool, 1, 2)]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, bs, 1), scale_map),
-            pl.BlockSpec((1, bs, 1), scale_map),
+            pl.BlockSpec((1, 1, bs, 1), kv_map),
+            pl.BlockSpec((1, 1, bs, 1), kv_map),
         ]
-        operands += [k_scales, v_scales]
+        operands += [jnp.swapaxes(k_scales, 1, 2)[..., None],
+                     jnp.swapaxes(v_scales, 1, 2)[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -229,7 +233,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, block_tables, context_lens,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
+        out_shape=out_struct((B, Hkv, G, hd), q.dtype, *operands),
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,

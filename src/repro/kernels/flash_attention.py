@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import resolve_kernel
+from repro.kernels.backend import mosaic_call, out_struct, resolve_kernel
 from repro.kernels.ref import flash_attention_ref
 
 # jax < 0.5 names this TPUCompilerParams; it was renamed to CompilerParams.
@@ -138,9 +138,10 @@ def flash_attention(
     if impl == "jnp":
         return _flash_attention_jnp(q, k, v, causal=causal, window=window,
                                     softcap=softcap)
-    return _flash_attention_pallas(
-        q, k, v, causal=causal, window=window, softcap=softcap,
-        block_q=block_q, block_kv=block_kv, interpret=interpret)
+    return mosaic_call(functools.partial(
+        _flash_attention_pallas, causal=causal, window=window,
+        softcap=softcap, block_q=block_q, block_kv=block_kv,
+        interpret=interpret), q, k, v, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap"))
@@ -197,7 +198,7 @@ def _flash_attention_pallas(q, k, v, *, causal, window, softcap,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
+        out_shape=out_struct((B, H, Sp, hd), q.dtype, qt, kt, vt),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),

@@ -9,6 +9,9 @@ threading here anymore.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import paged_decode_attention as _paged_decode
@@ -16,6 +19,7 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.pier_update import pier_update as _pier_update
 from repro.kernels.quantize import (dequantize_blockwise as _dequantize,
                                     quantize_blockwise as _quantize)
+from repro.kernels.ref import flash_attention_ref
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 
 
@@ -35,9 +39,33 @@ def flash_attention_supported(q, k, v, *, window: int = 0,
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0):
+    """Flash attention forward; differentiable for the training step.
+
+    The kernel has no backward of its own: the gradient is the VJP of the
+    ``kernels/ref.py`` oracle, recomputed from q, k and v.
+    """
+    return _trainable_flash(q, k, v, causal, window, softcap)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _trainable_flash(q, k, v, causal, window, softcap):
     return _flash(
         q, k, v, causal=causal, window=window, softcap=softcap,
         block_q=128, block_kv=128)
+
+
+def _trainable_flash_fwd(q, k, v, causal, window, softcap):
+    return _trainable_flash(q, k, v, causal, window, softcap), (q, k, v)
+
+
+def _trainable_flash_bwd(causal, window, softcap, res, g):
+    _, vjp = jax.vjp(functools.partial(
+        flash_attention_ref, causal=causal, window=window, softcap=softcap),
+        *res)
+    return vjp(g)
+
+
+_trainable_flash.defvjp(_trainable_flash_fwd, _trainable_flash_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import resolve_kernel
+from repro.kernels.backend import mosaic_call, out_struct, resolve_kernel
 from repro.kernels.ref import pier_update_ref
 
 _BLOCK = 4096  # lanes*32 panels: multiple of the (8,128) fp32 VMEM tile
@@ -66,9 +66,10 @@ def pier_update(
     if impl == "jnp":
         return _pier_update_jnp(anchor, momentum, delta, mu, lr,
                                 formulation=formulation)
-    return _pier_update_pallas(anchor, momentum, delta, mu, lr,
-                               formulation=formulation, block=block,
-                               interpret=interpret)
+    return mosaic_call(functools.partial(
+        _pier_update_pallas, formulation=formulation, block=block,
+        interpret=interpret), anchor, momentum, delta, mu, lr,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("formulation",))
@@ -107,8 +108,8 @@ def _pier_update_pallas(anchor, momentum, delta, mu, lr, *,
             pl.BlockSpec((block,), lambda i: (i,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
-            jax.ShapeDtypeStruct((np_,), momentum.dtype),
+            out_struct((np_,), jnp.float32, anchor, momentum, delta),
+            out_struct((np_,), momentum.dtype, anchor, momentum, delta),
         ],
         interpret=interpret,
     )(mu2, lr2, anchor, momentum, delta)

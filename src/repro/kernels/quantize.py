@@ -12,6 +12,9 @@ scale is 0 and the inverse is masked), so momentum-free leaves cost nothing
 in error. Both kernels stream (rows, block) panels through VMEM — the op is
 purely memory-bound, one pass is its roofline. ``block`` should be a
 multiple of 128 (lane width) on a real TPU; the interpreter accepts any.
+Scales travel as an ``(nblocks, 1)`` column inside the kernels: Mosaic
+refuses a 1-D block of ``_ROWS`` (not a multiple of 128), while an
+``(_ROWS, 1)`` block spans the whole minor dim.
 
 The pure-jnp oracles live in kernels/ref.py; the kernels execute the same
 ops elementwise so interpret-mode output matches the oracle bit for bit.
@@ -26,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.backend import resolve_kernel
+from repro.kernels.backend import mosaic_call, out_struct, resolve_kernel
 from repro.kernels.ref import dequantize_blockwise_ref, quantize_blockwise_ref
 
 _ROWS = 8  # quant blocks (= scale rows) per grid step: fp32 sublane tile
@@ -34,19 +37,19 @@ _ROWS = 8  # quant blocks (= scale rows) per grid step: fp32 sublane tile
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, qmax: float):
     x = x_ref[...].astype(jnp.float32)  # (R, B)
-    absmax = jnp.max(jnp.abs(x), axis=-1)  # (R,)
+    absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)  # (R, 1)
     # reciprocal-multiply, NOT division: XLA strength-reduces constant
     # divisions under jit but not eagerly, and the oracle must match bitwise
     scale = absmax * (1.0 / qmax)
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
-    q = jnp.clip(jnp.round(x * inv[:, None]), -qmax, qmax)
+    q = jnp.clip(jnp.round(x * inv), -qmax, qmax)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)  # (R, B)
-    o_ref[...] = q * s_ref[...][:, None]
+    o_ref[...] = q * s_ref[...]  # (R, B) * (R, 1)
 
 
 def _pad_rows(nb: int) -> int:
@@ -70,7 +73,9 @@ def quantize_blockwise(
     impl, interpret = resolve_kernel("quantize", interpret)
     if impl == "jnp":
         return _quantize_jnp(x, bits=bits, block=block)
-    return _quantize_pallas(x, bits=bits, block=block, interpret=interpret)
+    return mosaic_call(functools.partial(
+        _quantize_pallas, bits=bits, block=block, interpret=interpret),
+        x, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block"))
@@ -96,15 +101,15 @@ def _quantize_pallas(x, *, bits, block, interpret):
         in_specs=[pl.BlockSpec((_ROWS, block), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((_ROWS,), lambda i: (i,)),
+            pl.BlockSpec((_ROWS, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nbp, block), jnp.int8),
-            jax.ShapeDtypeStruct((nbp,), jnp.float32),
+            out_struct((nbp, block), jnp.int8, x2),
+            out_struct((nbp, 1), jnp.float32, x2),
         ],
         interpret=interpret,
     )(x2)
-    return q[:nb].reshape(nb * block), s[:nb]
+    return q[:nb].reshape(nb * block), s[:nb, 0]
 
 
 def dequantize_blockwise(
@@ -118,7 +123,9 @@ def dequantize_blockwise(
     impl, interpret = resolve_kernel("dequantize", interpret)
     if impl == "jnp":
         return _dequantize_jnp(q, scales, block=block)
-    return _dequantize_pallas(q, scales, block=block, interpret=interpret)
+    return mosaic_call(functools.partial(
+        _dequantize_pallas, block=block, interpret=interpret),
+        q, scales, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -146,10 +153,10 @@ def _dequantize_pallas(q, scales, *, block, interpret):
         grid=(nbp // _ROWS,),
         in_specs=[
             pl.BlockSpec((_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((_ROWS,), lambda i: (i,)),
+            pl.BlockSpec((_ROWS, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((_ROWS, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbp, block), jnp.float32),
+        out_shape=out_struct((nbp, block), jnp.float32, q2, s),
         interpret=interpret,
-    )(q2, s)
+    )(q2, s[:, None])
     return out[:nb].reshape(nb * block)

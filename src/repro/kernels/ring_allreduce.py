@@ -17,14 +17,13 @@ Three transports, one reduction (all reduced by the shared
 :func:`repro.kernels.ref.dequant_sum_sources`, so their numerics are
 identical bit for bit):
 
-- **ppermute ring** (CPU / tier-1 reference): a store-and-forward ring —
-  E−1 neighbor hops, each carrying the packed wire buffer + scales,
-  gathered into canonical source slots. Runs under ``vmap(axis_name=…)``
-  (the single-device test harness) and modern-jax shard_map.
+- **collective** (``"ring"``, the default off the TPU DMA lane): XLA's
+  all-gather of the packed wire buffer + scales into canonical source
+  slots. Runs under ``vmap(axis_name=…)`` (the single-device test
+  harness) and shard_map.
 - **one-hot psum**: each endpoint deposits its payload at its linearized
   slot of a zero ``(E, ·)`` buffer and psums — exact (one non-zero
-  contributor per slot) and the only gather jax 0.4.x partial-manual
-  shard_map can lower, so the distributed steps select it there.
+  contributor per slot); a cross-check of the gather, at E× its bytes.
 - **Pallas remote-DMA** (real TPU): :func:`ring_allgather_wire_tpu`
   forwards the wire buffers around the ring with
   ``pltpu.make_async_remote_copy`` (double-buffered slots, neighbor
@@ -68,7 +67,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import COMPILED, kernel_lane, on_tpu
+from repro import compat
+from repro.kernels.backend import (COMPILED, kernel_lane, mosaic_call, on_tpu,
+                                   out_struct)
 from repro.kernels.ref import (dequant_concat_sources,  # noqa: F401
                                dequant_sum_sources, pack_wire,
                                shard_slot_wire, unpack_wire,
@@ -120,46 +121,27 @@ def _axis_idx(axis_name: str, axis_coords) -> jax.Array:
     return jax.lax.axis_index(axis_name)
 
 
-def _ring_gather(x: jax.Array, axis_name: str, size: int, idx) -> jax.Array:
-    """All-gather ``x`` into canonical axis-index slots via E−1 ring hops.
-
-    Each hop forwards the buffer to the right neighbor (``ppermute`` —
-    on the wire this is exactly one payload per link per step); after hop
-    ``k`` a device holds source ``(idx − k − 1) mod E``. Works inside
-    modern-jax ``shard_map`` and under ``vmap(axis_name=...)`` (the
-    single-device test harness); jax 0.4.x partial-manual shard_map
-    cannot lower ppermute (XLA CHECK) — the distributed steps use
-    :func:`onehot_gather_wire` there instead.
-    """
-    out = jnp.zeros((size, *x.shape), x.dtype)
-    out = jax.lax.dynamic_update_index_in_dim(out, x, idx, 0)
-    buf = x
-    perm = [(i, (i + 1) % size) for i in range(size)]
-    for k in range(size - 1):
-        buf = jax.lax.ppermute(buf, axis_name, perm)
-        src = (idx - k - 1) % size
-        out = jax.lax.dynamic_update_index_in_dim(out, buf, src, 0)
-    return out
-
-
 def ring_gather_wire(w: jax.Array, s: jax.Array,
                      axis_names: Sequence[str],
-                     axis_sizes: Mapping[str, int],
-                     axis_coords=None) -> Tuple[jax.Array, jax.Array]:
-    """ppermute transport: gather every source's (wire bytes, scales).
+                     axis_sizes: Mapping[str, int]
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Collective transport: gather every source's (wire bytes, scales).
 
-    Multiple exchange axes compose as nested rings (right-to-left), so the
-    flattened leading axis is row-major over ``axis_names`` — the same
-    linearization the (G,)-stacked simulator uses for its group index.
-    Returns ``((E, nw) wire, (E, nb) scales)`` with E = Π sizes.
+    One XLA all-gather per exchange axis (right-to-left), so the flattened
+    leading axis is row-major over ``axis_names`` — the same linearization
+    the (G,)-stacked simulator uses for its group index. The gather is the
+    invariant kind: every endpoint holds the same canonical stack, so the
+    reduction over it is typed replicated for the ``P()`` outer-state
+    ``out_specs``. Runs inside ``shard_map`` and under
+    ``vmap(axis_name=...)`` (the single-device test harness). Returns
+    ``((E, nw) wire, (E, nb) scales)`` with E = Π sizes.
     """
     names = tuple(axis_names)
     _check_axis_sizes(names, axis_sizes)
     wg, sg = w[None], s[None]
     for ax in reversed(names):
-        idx = _axis_idx(ax, axis_coords)
-        wg = _ring_gather(wg, ax, axis_sizes[ax], idx)
-        sg = _ring_gather(sg, ax, axis_sizes[ax], idx)
+        wg = compat.all_gather_invariant(wg, ax)
+        sg = compat.all_gather_invariant(sg, ax)
     return (wg.reshape(-1, w.shape[0]), sg.reshape(-1, s.shape[0]))
 
 
@@ -219,8 +201,7 @@ def ring_scatter_wire(w_slots: jax.Array, s_slots: jax.Array,
         sg = _ring_scatter(s_slots, names[0], E, idx)
         return wg, sg
     wg_all, sg_all = ring_gather_wire(
-        w_slots.reshape(-1), s_slots.reshape(-1), names, axis_sizes,
-        axis_coords)
+        w_slots.reshape(-1), s_slots.reshape(-1), names, axis_sizes)
     wg = jax.lax.dynamic_index_in_dim(
         wg_all.reshape(E, *w_slots.shape), idx, 1, keepdims=False)
     sg = jax.lax.dynamic_index_in_dim(
@@ -232,7 +213,7 @@ def onehot_scatter_wire(w_slots: jax.Array, s_slots: jax.Array,
                         axis_names: Sequence[str],
                         axis_sizes: Mapping[str, int],
                         axis_coords=None) -> Tuple[jax.Array, jax.Array]:
-    """psum reduce-scatter transport (jax 0.4.x partial-manual fallback).
+    """psum reduce-scatter transport (a cross-check of the ring lane).
 
     Deposits the per-slot stack at the canonical source row of a zero
     (E, E, ·) cube and psums — every endpoint then slices the column of
@@ -263,9 +244,7 @@ def onehot_gather_wire(w: jax.Array, s: jax.Array,
     all-zero ``(E, ...)`` buffer and psums over the exchange axes — each
     slot has exactly one non-zero contributor, so the gather is exact for
     the int values and the (non-negative) fp32 scales in any reduction
-    order. This is the transport jax 0.4.x partial-manual shard_map can
-    actually lower (psum works where ppermute CHECK-fails); the wire
-    realism lives in the TPU remote-DMA path either way.
+    order — a cross-check of the all-gather, at E× its bytes.
     """
     names = tuple(axis_names)
     _check_axis_sizes(names, axis_sizes)
@@ -314,8 +293,8 @@ def _ring_allgather_kernel(x_ref, out_ref, comm_buf, send_sem, recv_sem, *,
     comm_buf[0] = x_ref[...]
 
     barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id=left)
-    pltpu.semaphore_signal(barrier, inc=1, device_id=right)
+    pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: left})
+    pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: right})
     pltpu.semaphore_wait(barrier, 2)
 
     for step in range(num_devices - 1):
@@ -326,8 +305,10 @@ def _ring_allgather_kernel(x_ref, out_ref, comm_buf, send_sem, recv_sem, *,
             dst_ref=comm_buf.at[nxt],
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[nxt],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            # the peer's coordinate along the ring axis; every other mesh
+            # coordinate is this device's own
+            device_id={axis_name: right},
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
         rdma.wait()
@@ -342,7 +323,9 @@ def _ring_allgather_tpu_1d(x: jax.Array, axis_name: str,
     return pl.pallas_call(
         functools.partial(_ring_allgather_kernel, num_devices=size,
                           axis_name=axis_name),
-        out_shape=jax.ShapeDtypeStruct((size, n), x.dtype),
+        # every device ends with the same canonical stack
+        out_shape=out_struct((size, n), x.dtype, x,
+                             invariant_over=(axis_name,)),
         # whole-array VMEM refs: Mosaic can index these directly, unlike
         # ANY-space refs; _WIRE_CHUNK_BYTES bounds the footprint
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -374,12 +357,13 @@ def ring_allgather_wire_tpu(w: jax.Array, s: jax.Array, axis_name: str,
     # data-independent, and any two concurrently-scheduled collectives
     # sharing an id would alias one barrier semaphore and desynchronize
     for lo in range(0, nw, chunk):
-        parts.append(_ring_allgather_tpu_1d(
-            w[lo:lo + chunk], axis_name, size,
-            collective_id=_next_collective_id()))
+        parts.append(mosaic_call(functools.partial(
+            _ring_allgather_tpu_1d, axis_name=axis_name, size=size,
+            collective_id=_next_collective_id()), w[lo:lo + chunk]))
     wg = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
-    sg = _ring_allgather_tpu_1d(s, axis_name, size,
-                                collective_id=_next_collective_id())
+    sg = mosaic_call(functools.partial(
+        _ring_allgather_tpu_1d, axis_name=axis_name, size=size,
+        collective_id=_next_collective_id()), s)
     return wg, sg
 
 
@@ -408,7 +392,7 @@ def _shard_scatter_kernel(slots_ref, out_ref, send_buf, recv_buf,
     for off in range(1, num_devices):
         pltpu.semaphore_signal(
             barrier, inc=1,
-            device_id=jax.lax.rem(my + off, num_devices))
+            device_id={axis_name: jax.lax.rem(my + off, num_devices)})
     pltpu.semaphore_wait(barrier, num_devices - 1)
 
     for k in range(1, num_devices):
@@ -422,8 +406,8 @@ def _shard_scatter_kernel(slots_ref, out_ref, send_buf, recv_buf,
             dst_ref=recv_buf.at[slot],
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[slot],
-            device_id=(dst,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id={axis_name: dst},
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
         rdma.wait()
@@ -438,7 +422,7 @@ def _shard_scatter_tpu_1d(slots: jax.Array, axis_name: str, size: int,
     return pl.pallas_call(
         functools.partial(_shard_scatter_kernel, num_devices=size,
                           axis_name=axis_name),
-        out_shape=jax.ShapeDtypeStruct((size, n), slots.dtype),
+        out_shape=out_struct((size, n), slots.dtype, slots),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -464,12 +448,13 @@ def shard_scatter_wire_tpu(w_slots: jax.Array, s_slots: jax.Array,
     chunk = max(_WIRE_CHUNK_BYTES // max(w_slots.dtype.itemsize, 1), 1)
     parts = []
     for lo in range(0, nw, chunk):
-        parts.append(_shard_scatter_tpu_1d(
-            w_slots[:, lo:lo + chunk], axis_name, size,
-            collective_id=_next_collective_id()))
+        parts.append(mosaic_call(functools.partial(
+            _shard_scatter_tpu_1d, axis_name=axis_name, size=size,
+            collective_id=_next_collective_id()), w_slots[:, lo:lo + chunk]))
     wg = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
-    sg = _shard_scatter_tpu_1d(s_slots, axis_name, size,
-                               collective_id=_next_collective_id())
+    sg = mosaic_call(functools.partial(
+        _shard_scatter_tpu_1d, axis_name=axis_name, size=size,
+        collective_id=_next_collective_id()), s_slots)
     return wg, sg
 
 
@@ -488,20 +473,17 @@ def resolve_transport(*, axis_names: Sequence[str],
     hardware) AND the process must actually run on TPU devices (a forced
     ``tpu-mosaic`` backend on CPU still falls back) — and it only
     composes over a single exchange axis. Everything else resolves to the
-    collective transports: the ppermute ring where shard_map can lower it
-    (modern jax), one-hot psum on jax 0.4.x partial-manual shard_map.
+    collective ``"ring"`` transport.
 
     ``use_pallas=True`` (the default) answers "best transport this
     backend could use"; strategies pass their actual ``ReduceCtx``
     setting at dispatch time.
     """
-    from repro import compat
-
     names = tuple(axis_names)
     if (use_pallas and len(names) == 1 and on_tpu()
             and kernel_lane("ring_allreduce") == COMPILED):
         return "dma"
-    return "ring" if compat.HAS_NEW_SHARD_MAP else "psum"
+    return "ring"
 
 
 def ring_allreduce_quantized(q: jax.Array, s: jax.Array, *,
@@ -541,7 +523,7 @@ def ring_allreduce_quantized(q: jax.Array, s: jax.Array, *,
         wg, sg = ring_allgather_wire_tpu(
             w, s, names[0], axis_sizes[names[0]])
     elif transport == "ring":
-        wg, sg = ring_gather_wire(w, s, names, axis_sizes, axis_coords)
+        wg, sg = ring_gather_wire(w, s, names, axis_sizes)
     elif transport == "psum":
         wg, sg = onehot_gather_wire(w, s, names, axis_sizes, axis_coords)
     else:
@@ -573,7 +555,7 @@ def reduce_scatter_qs(q: jax.Array, s: jax.Array, *,
 
     Per-device wire traffic on the ring/dma transports is
     (E−1)/E·payload — the reduce-scatter win. The psum transport is the
-    jax 0.4.x partial-manual correctness lane (gather-sized traffic).
+    correctness cross-check (gather-sized traffic).
     """
     names = tuple(axis_names)
     E = 1
@@ -627,7 +609,7 @@ def allgather_qs(q2: jax.Array, s2: jax.Array, *,
         wg, sg = ring_allgather_wire_tpu(
             w2, s2, names[0], axis_sizes[names[0]])
     elif transport == "ring":
-        wg, sg = ring_gather_wire(w2, s2, names, axis_sizes, axis_coords)
+        wg, sg = ring_gather_wire(w2, s2, names, axis_sizes)
     elif transport == "psum":
         wg, sg = onehot_gather_wire(w2, s2, names, axis_sizes, axis_coords)
     else:

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.backend import resolve_kernel
+from repro.kernels.backend import mosaic_call, out_struct, resolve_kernel
 from repro.kernels.ref import rmsnorm_ref
 
 
@@ -42,8 +42,9 @@ def rmsnorm(
     impl, interpret = resolve_kernel("rmsnorm", interpret)
     if impl == "jnp":
         return _rmsnorm_jnp(x, scale, eps=eps)
-    return _rmsnorm_pallas(x, scale, eps=eps, block_rows=block_rows,
-                           interpret=interpret)
+    return mosaic_call(functools.partial(
+        _rmsnorm_pallas, eps=eps, block_rows=block_rows,
+        interpret=interpret), x, scale, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
@@ -72,7 +73,7 @@ def _rmsnorm_pallas(x, scale, *, eps, block_rows, interpret):
             pl.BlockSpec((D,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rp, D), x.dtype),
+        out_shape=out_struct((rp, D), x.dtype, x2, scale),
         interpret=interpret,
     )(x2, scale)
     return out[:rows].reshape(orig_shape)

@@ -1,7 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init). 512 host devices back the 2x16x16 production mesh.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
+# The lines above MUST run before any other import (jax locks the platform
+# and the device count at first init). A compile tool: it runs on the CPU,
+# never on a chip, and so do the children of --all. 512 host devices back
+# the 2x16x16 production mesh; the appended count overrides an earlier one
+# and every other flag of the caller stays.
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the production meshes and extract the roofline terms.

@@ -29,10 +29,6 @@ from jax.sharding import Mesh
 
 from repro import compat
 
-# jax < 0.5 has no jax.sharding.AxisType; all-auto is the implicit default
-# there, which is what every mesh in this module asks for.
-AUTO = compat.AXIS_TYPE_AUTO
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -1,6 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# Must precede all other imports (jax locks device count on first init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
+# Must precede all other imports (jax locks the platform and the device
+# count on first init): a CPU compile tool, as launch/dryrun.py is.
 
 """§Perf hillclimb driver: lower/compile named VARIANTS of a (arch × shape)
 pair and report the roofline-term deltas vs the paper-faithful baseline.

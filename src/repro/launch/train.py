@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
-from typing import Optional
+from pathlib import Path
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +36,67 @@ from repro.launch import mesh as M
 from repro.parallel.steps import build_train_steps
 from repro.sync import (ChurnSchedule, MembershipController,
                         ModelDelayController, resolve_strategy)
+
+
+# The checkout root (src/repro/launch/train.py -> the repo).
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+# The kernels a training step runs with ParallelConfig.use_pallas on.
+TRAINED_KERNELS = ("flash_attention", "rmsnorm", "pier_update", "quantize",
+                   "dequantize")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is the cache (jax reads it
+    itself) and no other directory is set. Otherwise the cache is
+    ``.jax_cache/`` at the checkout root: a fixed path, so that a second
+    run finds what the first compiled.
+    """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
+def pallas_kernels_compiled() -> bool:
+    """Whether the resolved backend compiles every kernel a step runs.
+
+    The launcher's ``use_pallas``: on a TPU the training step runs the
+    compiled Pallas kernels; in the interpret and jnp-ref lanes it keeps
+    the jnp paths.
+    """
+    return all(kbackend.kernel_lane(k) == kbackend.COMPILED
+               for k in TRAINED_KERNELS)
+
+
+def resolve_mesh_shape(mesh_arg: str, groups: Optional[int],
+                       num_devices: int) -> Tuple[int, int, int]:
+    """``(data_outer, data_inner, model)`` for the devices present.
+
+    ``mesh_arg`` ("G,D,M") must use every device. Without it the groups
+    split the devices evenly along ``data_inner``; ``groups`` defaults to
+    2 where the device count is even and to 1 where it is odd (one chip).
+    Raises ``ValueError`` with the reason when the request does not fit.
+    """
+    if mesh_arg:
+        shape = tuple(int(x) for x in mesh_arg.split(","))
+        if len(shape) != 3:
+            raise ValueError(f"--mesh {mesh_arg!r} needs three sizes "
+                             f"(data_outer,data_inner,model)")
+        if shape[0] * shape[1] * shape[2] != num_devices:
+            raise ValueError(f"--mesh {mesh_arg} spans "
+                             f"{shape[0] * shape[1] * shape[2]} devices; "
+                             f"this host has {num_devices}")
+        return shape
+    if groups is None:
+        groups = 2 if num_devices % 2 == 0 else 1
+    if groups < 1 or num_devices % groups:
+        raise ValueError(f"--groups {groups} must divide the {num_devices} "
+                         f"device(s) on this host: each group needs its own")
+    return (groups, num_devices // groups, 1)
 
 
 def resolve_auto_sync_delay(tc: TrainConfig, mc: ModelConfig,
@@ -571,11 +634,13 @@ def main(argv=None):
                     help="donor for a rejoining group's params: the "
                          "freshly installed anchor, or the latest "
                          "complete checkpoint (needs --checkpoint-dir)")
-    ap.add_argument("--groups", type=int, default=2,
-                    help="Pier groups (data_outer)")
+    ap.add_argument("--groups", type=int, default=None,
+                    help="Pier groups (data_outer); default 2 on an even "
+                         "device count, else 1")
     ap.add_argument("--mesh", default="",
                     help="mesh shape e.g. 2,2,2 = data_outer,data_inner,model"
-                         " (default: all devices as 1D data_inner)")
+                         " (default: --groups groups, each over an equal "
+                         "share of the devices along data_inner)")
     ap.add_argument("--lr", type=float, default=4e-4)
     ap.add_argument("--offload", action="store_true")
     ap.add_argument("--checkpoint-dir", default="")
@@ -613,15 +678,16 @@ def main(argv=None):
 
     mc = (get_reduced_config(args.arch) if args.reduced
           else get_config(args.arch))
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split(","))
-    else:
-        n = jax.device_count()
-        shape = (args.groups, max(n // args.groups, 1), 1)
+    try:
+        shape = resolve_mesh_shape(args.mesh, args.groups,
+                                   jax.device_count())
+    except ValueError as e:
+        ap.error(str(e))
+    use_compile_cache()
     mesh = M.small_mesh(shape, ("data_outer", "data_inner", "model"))
     pc = ParallelConfig(
         data_axis_size=shape[0] * shape[1], model_axis_size=shape[2],
-        data_outer=shape[0])
+        data_outer=shape[0], use_pallas=pallas_kernels_compiled())
     sync_delay = (args.sync_delay if args.sync_delay == "auto"
                   else int(args.sync_delay))
     tc = TrainConfig(
@@ -652,12 +718,19 @@ def main(argv=None):
             pc.num_groups, cfg=mcfg,
             schedule=ChurnSchedule.parse(args.churn_script))
     strategy = resolve_strategy(tc)
+    # Pallas kernels cannot be partitioned over the in-group axes: they
+    # run replicated there (kernels/backend.py:mosaic_call) — say so
+    replicated = (kbackend.kernel_replicated_axes(mesh, M.manual_axes(mesh))
+                  if pc.use_pallas else ())
     print(f"arch={mc.name} optimizer={tc.optimizer} mesh={shape} "
           f"groups={pc.num_groups} devices={jax.device_count()} "
           f"outer_sync={strategy.name} "
           f"kernel_backend={kbackend.resolve_backend().name} "
+          f"use_pallas={pc.use_pallas} "
           f"transport={strategy.transport_name(mesh)}"
-          + (f" churn={args.churn_script}" if args.churn_script else ""))
+          + (f" churn={args.churn_script}" if args.churn_script else "")
+          + (f" pallas_kernels_replicated_over={','.join(replicated)}"
+             if replicated else ""))
     trainer = Trainer(mc, tc, pc, mesh,
                       checkpoint_dir=args.checkpoint_dir or None,
                       chip_hint=args.chip,

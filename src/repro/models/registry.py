@@ -47,7 +47,14 @@ def loss_fn(
     mask = (labels >= 0).astype(jnp.float32)
     labels_safe = jnp.maximum(labels, 0)
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels_safe[..., None], axis=-1)[..., 0]
+    # A masked sum, not take_along_axis: XLA's SPMD partitioner CHECK-fails
+    # (spmd_partitioner_util.cc, HandleGather) on the batched gather when
+    # the batch dim and the vocab dim are both auto-sharded inside the
+    # partial-manual shard_map. Exactly one term is non-zero, so the sum
+    # is the gathered logit bit for bit.
+    vocab_ids = jnp.arange(logits.shape[-1], dtype=labels_safe.dtype)
+    gold = jnp.sum(jnp.where(labels_safe[..., None] == vocab_ids, logits, 0.0),
+                   axis=-1)
     nll = (logz - gold) * mask
     denom = jnp.maximum(jnp.sum(mask), 1.0)
     loss = jnp.sum(nll) / denom

@@ -255,7 +255,7 @@ def build_train_steps(
         if manual:
             # grads are varying over the manual (group) axes; the zero init
             # must carry the same varying-mesh-axes annotation for the scan
-            acc0 = compat.pvary(acc0, tuple(manual))
+            acc0 = compat.mark_varying(acc0, tuple(manual))
         (gsum, lsum), _ = jax.lax.scan(mb_body, acc0, micro)
         inv = 1.0 / nm
         return jax.tree.map(lambda g: g * inv, gsum), lsum * inv

@@ -47,18 +47,31 @@ from repro.sync.base import (OuterSyncStrategy, ReduceCtx, SyncPlan,
                              weighted_psum_mean, weighted_stack_mean)
 
 
-def _can_pad_in_manual() -> bool:
-    """Whether in-graph pad/slice of auto-sharded values inside the
-    partial-manual shard_map region is safe.
+def _lone_endpoint(payload, ctx: ReduceCtx):
+    """The delivered payload when the exchange has a single endpoint.
 
-    jaxlib 0.4.x trips an XLA partitioner CHECK (hlo_sharding_util
-    IsManualSubgroup) repartitioning padded flat payloads there, so
-    :class:`Sharded` keeps ragged leaves on the replicated round trip;
-    modern jax (the new shard_map, jax >= 0.5) lowers the pad fine and
-    takes the shard-local quantize path. Module-level so tests can
-    exercise the gate both ways by monkeypatching.
+    It is the mean already; a psum over the size-1 exchange axes adds
+    nothing and types it replicated, as the ``P()`` outer-state
+    ``out_specs`` require (a wire exchange over E > 1 gets that from its
+    invariant gather).
     """
-    return compat.HAS_NEW_SHARD_MAP
+    if not ctx.exchange_axes:
+        return payload
+    return jax.lax.psum(payload, ctx.exchange_axes)
+
+
+def _from_first(x, ctx: ReduceCtx):
+    """``x`` of the device at fast-axes coordinate 0, on every device.
+
+    A psum with one non-zero contributor: exact, and typed invariant over
+    ``ctx.fast_axes``.
+    """
+    from repro.kernels.ring_allreduce import _linear_exchange_idx
+
+    _, idx = _linear_exchange_idx(ctx.fast_axes, ctx.axis_sizes,
+                                  ctx.axis_coords)
+    return jax.lax.psum(jnp.where(idx == 0, x, jnp.zeros_like(x)),
+                        ctx.fast_axes)
 
 
 @dataclass(frozen=True)
@@ -228,7 +241,7 @@ class Int8Wire(OuterSyncStrategy):
         payload_local = dequant(q, s)[:n].reshape(c.shape)
         new_r = c - payload_local
         if not ctx.exchange_axes or ctx.exchange_size() <= 1:
-            return payload_local, new_r
+            return _lone_endpoint(payload_local, ctx), new_r
         # ctx.weights rides in exchange order (row-major over the
         # exchange axes — pod-level under Hierarchical, which narrows
         # the ctx with pod weight sums); None keeps the 1/E sum.
@@ -271,7 +284,7 @@ class Int8Wire(OuterSyncStrategy):
         if not ctx.exchange_axes or ctx.exchange_size() <= 1:
             # no exchange: deliver the local dequant; the gather-leg
             # residual has nothing new to absorb
-            return payload_local, (new_r1, r2)
+            return _lone_endpoint(payload_local, ctx), (new_r1, r2)
         E = ctx.exchange_size()
         sb = wire_shard_blocks(int(s.shape[0]), E)
         slot = sb * self.block
@@ -452,11 +465,8 @@ class Sharded(OuterSyncStrategy):
       every shard holds whole quantization blocks, so blockwise absmax
       never crosses a shard boundary and the blocks are bitwise what the
       unsharded :class:`Quantized` produces. Ragged leaves pad in-graph
-      to whole per-shard blocks and still quantize shard-locally on
-      modern jax; on jaxlib 0.4.x (where the in-graph pad/slice trips a
-      partitioner CHECK — see :func:`_can_pad_in_manual`) they fall back
-      to the inner replicated round trip. Same numeric model, same
-      simulator tolerance.
+      to whole per-shard blocks and still quantize shard-locally. Same
+      numeric model, same simulator tolerance.
     - ``Sharded(Int8Wire(...))``: the explicit reduce-scatter +
       all-gather wire exchange (DESIGN.md §14). The combinator force-
       normalizes the inner's ``reduce_scatter=True`` — a full-payload
@@ -530,25 +540,10 @@ class Sharded(OuterSyncStrategy):
             return d, rr
         if isinstance(self.inner, Quantized):
             block = self.inner.block
-            if d.size % (block * max(ctx.auto_size(), 1)) == 0:
-                d, r = self._compress_sharded(d, r, ctx)
-            elif _can_pad_in_manual():
-                # modern jax: pad the flat payload to whole per-shard
-                # blocks in-graph and take the shard-local path anyway
-                d, r = self._compress_sharded(d, r, ctx, pad=True)
-            else:
-                # Ragged leaf on jaxlib 0.4.x: padding (or slicing) the
-                # flat payload inside the partial-manual region trips an
-                # XLA partitioner CHECK
-                # (hlo_sharding_util IsManualSubgroup — the same class
-                # of CHECK that gates md_dryrun_mini), so leaves that
-                # don't divide into whole per-shard blocks keep the
-                # inner strategy's replicated round trip. Only small
-                # odd leaves land here; the big block-divisible
-                # matrices — the bytes that matter — still shard.
-                d, r = compress_delta(d, r, bits=self.inner.bits,
-                                      block=block,
-                                      use_pallas=ctx.use_pallas)
+            # ragged leaves pad the flat payload to whole per-shard
+            # blocks in-graph and take the shard-local path too
+            ragged = d.size % (block * max(ctx.auto_size(), 1)) != 0
+            d, r = self._compress_sharded(d, r, ctx, pad=ragged)
         if ctx.exchange_axes:
             if ctx.weight is not None:
                 d = weighted_psum_mean(d, ctx.weight, ctx.exchange_axes)
@@ -564,12 +559,11 @@ class Sharded(OuterSyncStrategy):
         dim. Without ``pad`` the caller guarantees the leaf divides into
         whole per-shard blocks (``n % (block·shards) == 0``), so the
         quantize/dequantize round trip never crosses a shard boundary and
-        no in-graph pad/slice is needed. With ``pad`` (ragged leaves on
-        modern jax — :func:`_can_pad_in_manual`) the flat payload is
-        zero-padded to the next whole per-shard block multiple first and
-        the round trip sliced back; zero padding quantizes to zero scales
-        and dequantizes to exact zeros, so the blocks covering real data
-        are bitwise unchanged.
+        no in-graph pad/slice is needed. With ``pad`` (ragged leaves) the
+        flat payload is zero-padded to the next whole per-shard block
+        multiple first and the round trip sliced back; zero padding
+        quantizes to zero scales and dequantizes to exact zeros, so the
+        blocks covering real data are bitwise unchanged.
         """
         from jax.sharding import PartitionSpec as P
 
@@ -680,10 +674,14 @@ class Hierarchical(OuterSyncStrategy):
                 d = jax.lax.pmean(d, ctx.fast_axes)  # stage 1: fast, fp32
                 inner_ctx = ctx.narrowed(ctx.slow_axes)
         d, r = self.inner.reduce_leaf(d, r, tc, inner_ctx)
-        if r is not None and ctx.fast_axes and self.inner.needs_residual:
-            # the residual stopped varying over the fast axes at the
-            # stage-1 pmean; re-mark it for the stacked P(manual) spec
-            r = compat.pvary(r, ctx.fast_axes)
+        if ctx.fast_axes and self.inner.needs_residual:
+            # each group compressed the pod mean plus its *own* residual,
+            # so the delivered payload is typed varying over the fast
+            # axes; every pod delivers its first group's payload (the
+            # simulator's pod representative), typed replicated
+            d = _from_first(d, ctx)
+            if r is not None:
+                r = compat.mark_varying(r, ctx.fast_axes)
         return d, r
 
     def sim_reduce(self, delta, residual, tc, *, num_pods=1,

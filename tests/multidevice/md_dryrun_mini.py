@@ -1,9 +1,9 @@
 """Mini dry-run: the dryrun machinery end-to-end with a reduced arch on a
 (2,2,2) pier mesh (pod-less) and a multi-pod analogue.
 
-NOTE: importing repro.launch.dryrun sets XLA_FLAGS to 512 host devices
-before jax initializes (by design — its first two lines); the small meshes
-here use the first 8 of them.
+NOTE: importing repro.launch.dryrun appends a 512 host-device count to
+XLA_FLAGS before jax initializes (by design — its first lines); the small
+meshes here use the first 8 of them.
 """
 
 from repro.launch.dryrun import (  # noqa: E402  (must be first: sets XLA_FLAGS)

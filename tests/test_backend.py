@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.kernels import backend as kb
 from repro.kernels import ops as kops
 from repro.kernels.decode_attention import (paged_decode_attention,
@@ -234,14 +233,22 @@ def test_jnp_ref_needs_no_pallas(monkeypatch):
 
 
 def test_transport_off_tpu_is_collective():
-    expected = "ring" if compat.HAS_NEW_SHARD_MAP else "psum"
-    assert resolve_transport(axis_names=("data_outer",)) == expected
-    assert resolve_transport(axis_names=("pod", "data_outer")) == expected
+    assert resolve_transport(axis_names=("data_outer",)) == "ring"
+    assert resolve_transport(axis_names=("pod", "data_outer")) == "ring"
 
 
 def test_transport_dma_needs_tpu_and_compiled_lane(monkeypatch):
-    fallback = "ring" if compat.HAS_NEW_SHARD_MAP else "psum"
+    fallback = "ring"
     _fake_platform(monkeypatch, "tpu")
+    # the remote-DMA ring does not compile for the chip yet: its tpu-mosaic
+    # lane is the jnp one, so a TPU takes the collective transport
+    assert kb.kernel_lane("ring_allreduce") == kb.JNP
+    assert resolve_transport(axis_names=("data_outer",)) == fallback
+    # with the lane compiled, a single-axis TPU exchange takes the ring
+    caps = dict(kb.KERNEL_CAPS)
+    caps["ring_allreduce"] = {**caps["ring_allreduce"],
+                              "tpu-mosaic": kb.COMPILED}
+    monkeypatch.setattr(kb, "KERNEL_CAPS", caps)
     assert resolve_transport(axis_names=("data_outer",)) == "dma"
     # dma never spans multiple exchange axes, never runs without pallas
     assert resolve_transport(
@@ -262,11 +269,10 @@ def test_sync_plans_name_their_transport():
     from repro.sync.strategies import Chunked, FlatFP32, Int8Wire
 
     pshapes = {"w": jax.ShapeDtypeStruct((64,), jnp.float32)}
-    expected = "ring" if compat.HAS_NEW_SHARD_MAP else "psum"
     assert FlatFP32().plan(pshapes, None).transport == "collective"
-    assert Int8Wire().plan(pshapes, None).transport == expected
+    assert Int8Wire().plan(pshapes, None).transport == "ring"
     assert Chunked(inner=Int8Wire(), num_chunks=2).plan(
-        pshapes, None).transport == expected
+        pshapes, None).transport == "ring"
 
 
 # ---------------------------------------------------------------------------

@@ -12,20 +12,12 @@ import pytest
 HERE = os.path.dirname(__file__)
 SRC = os.path.abspath(os.path.join(HERE, "..", "src"))
 
-from repro import compat  # noqa: E402  (conftest puts src on sys.path)
-
 SCRIPTS = [
     "md_steps.py",
     "md_equivalence.py",
     "md_membership.py",
     "md_7b_dryrun.py",
-    pytest.param(
-        "md_dryrun_mini.py",
-        marks=pytest.mark.skipif(
-            not compat.HAS_NEW_SHARD_MAP,
-            reason="jaxlib 0.4.x partial-manual SPMD hits an XLA CHECK "
-                   "(hlo_sharding_util IsManualSubgroup) compiling the MoE "
-                   "dry-run; needs jax>=0.5 shard_map")),
+    "md_dryrun_mini.py",
 ]
 
 

@@ -547,37 +547,30 @@ def test_strategy_warmup_scale_reaches_controller():
 
 
 # ---------------------------------------------------------------------------
-# jaxlib version gate for ragged sharded leaves (satellite)
+# ragged sharded leaves
 # ---------------------------------------------------------------------------
 
 
-def test_can_pad_in_manual_gate_both_ways(monkeypatch):
-    """Sharded(Quantized) ragged leaves: shard-local pad path when the
-    gate is open (modern jax), replicated compress_delta fallback when
-    closed (jaxlib 0.4.x partitioner CHECK). Both keep the exact
-    error-feedback identity c == payload + residual'."""
-    from repro import compat
+def test_sharded_quantized_ragged_leaf_pads_shard_locally():
+    """Sharded(Quantized) ragged leaves pad to whole blocks in-graph and
+    quantize the same blocks as the unsharded Quantized round trip, with
+    the exact error-feedback identity c == payload + residual'."""
     from repro.sync import ReduceCtx
-    from repro.sync import strategies as S
-
-    assert S._can_pad_in_manual() == compat.HAS_NEW_SHARD_MAP
 
     ctx = ReduceCtx(manual=(), fast_axes=(), slow_axes=(),
                     exchange_axes=(), axis_sizes={})
-    st = Sharded(inner=Quantized(8, BLOCK))
     n = BLOCK * 2 + 7  # ragged: does not divide block * auto_size
     d = jax.random.normal(jax.random.PRNGKey(11), (n,))
     r = 0.01 * jax.random.normal(jax.random.PRNGKey(12), (n,))
     tc = _tc()
 
-    outs = {}
-    for gate in (False, True):
-        monkeypatch.setattr(S, "_can_pad_in_manual", lambda: gate)
-        payload, new_r = st.reduce_leaf(d, r, tc, ctx)
-        assert payload.shape == (n,) and new_r.shape == (n,)
-        np.testing.assert_allclose(
-            np.asarray(payload + new_r), np.asarray(d + r), atol=1e-6)
-        outs[gate] = (np.asarray(payload), np.asarray(new_r))
-    # same numeric model either way: both paths quantize the same blocks
-    np.testing.assert_allclose(outs[False][0], outs[True][0], atol=1e-6)
-    np.testing.assert_allclose(outs[False][1], outs[True][1], atol=1e-6)
+    payload, new_r = Sharded(inner=Quantized(8, BLOCK)).reduce_leaf(
+        d, r, tc, ctx)
+    assert payload.shape == (n,) and new_r.shape == (n,)
+    np.testing.assert_allclose(
+        np.asarray(payload + new_r), np.asarray(d + r), atol=1e-6)
+    ref_payload, ref_r = Quantized(8, BLOCK).reduce_leaf(d, r, tc, ctx)
+    np.testing.assert_allclose(np.asarray(payload), np.asarray(ref_payload),
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(new_r), np.asarray(ref_r),
+                               atol=1e-6)
