@@ -1,9 +1,9 @@
 """Sharded input pipeline for the mesh trainer.
 
-Produces global device arrays laid out over the mesh's data axes with
-background prefetch. Each Pier group consumes a *disjoint* slice of the
-stream (the group's data-parallel shard), matching the paper's Megatron
-data loader semantics.
+Produces global device arrays laid out over the mesh's data axes, with the
+host batches made ahead by a background thread. Each Pier group consumes a
+*disjoint* slice of the stream (the group's data-parallel shard), matching
+the paper's Megatron data loader semantics.
 """
 
 from __future__ import annotations
@@ -14,6 +14,7 @@ from typing import Callable, Dict, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ModelConfig, TrainConfig
@@ -23,11 +24,18 @@ from repro.data.synthetic import MarkovLM
 class DataPipeline:
     """Iterator of sharded training batches.
 
+    A producer thread calls ``make_batch`` and stages up to ``prefetch``
+    host (numpy) batches in a queue; ``__next__`` takes the next one and
+    puts it on the mesh (``jax.device_put``) on the consumer's thread.
+    Under a running profiler the three parts show as host spans:
+    ``pipeline.make_batch`` on the producer's thread,
+    ``pipeline.queue_wait`` and ``pipeline.device_put`` on the consumer's.
+
     Args:
       mesh: the (refined) device mesh.
       batch_axes: mesh axis name(s) sharding dim 0 of every array.
       make_batch: fn(step) -> dict of host numpy arrays (global shape).
-      prefetch: number of batches to stage ahead.
+      prefetch: number of host batches to stage ahead.
     """
 
     def __init__(
@@ -58,7 +66,8 @@ class DataPipeline:
         step, batch = 0, None
         while not self._stop.is_set():
             if batch is None:
-                batch = self.make_batch(step)
+                with TraceAnnotation("pipeline.make_batch", step=step):
+                    batch = self.make_batch(step)
             try:
                 self._q.put(batch, timeout=1.0)
                 step, batch = step + 1, None
@@ -69,9 +78,12 @@ class DataPipeline:
         return self
 
     def __next__(self) -> Dict[str, jax.Array]:
-        batch = self._q.get()
+        with TraceAnnotation("pipeline.queue_wait", step=self._step):
+            batch = self._q.get()
+        with TraceAnnotation("pipeline.device_put", step=self._step):
+            out = self._shard(batch)
         self._step += 1
-        return self._shard(batch)
+        return out
 
     def close(self):
         """Stop the producer and wait for it: a thread still inside jax

@@ -237,6 +237,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, block_tables, context_lens,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(block_tables, context_lens, *operands)
     return out.reshape(B, H, hd)
 

@@ -208,5 +208,8 @@ def _flash_attention_pallas(q, k, v, *, causal, window, softcap,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        # the trace's name for the kernel, which the benchmark's roofline
+        # reader of the attention forward matches
+        name="_flash_attention_pallas",
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :S], 1, 2)

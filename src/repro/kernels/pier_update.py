@@ -112,5 +112,6 @@ def _pier_update_pallas(anchor, momentum, delta, mu, lr, *,
             out_struct((np_,), momentum.dtype, anchor, momentum, delta),
         ],
         interpret=interpret,
+        name="pier_update",
     )(mu2, lr2, anchor, momentum, delta)
     return p_new[:n], m_new[:n]

@@ -108,6 +108,7 @@ def _quantize_pallas(x, *, bits, block, interpret):
             out_struct((nbp, 1), jnp.float32, x2),
         ],
         interpret=interpret,
+        name="quantize",
     )(x2)
     return q[:nb].reshape(nb * block), s[:nb, 0]
 
@@ -158,5 +159,6 @@ def _dequantize_pallas(q, scales, *, block, interpret):
         out_specs=pl.BlockSpec((_ROWS, block), lambda i: (i, 0)),
         out_shape=out_struct((nbp, block), jnp.float32, q2, s),
         interpret=interpret,
+        name="dequantize",
     )(q2, s[:, None])
     return out[:nb].reshape(nb * block)

@@ -336,6 +336,7 @@ def _ring_allgather_tpu_1d(x: jax.Array, axis_name: str,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=_CompilerParams(collective_id=collective_id),
+        name="ring_allgather",
     )(x)
 
 
@@ -432,6 +433,7 @@ def _shard_scatter_tpu_1d(slots: jax.Array, axis_name: str, size: int,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=_CompilerParams(collective_id=collective_id),
+        name="shard_scatter",
     )(slots)
 
 

@@ -75,5 +75,6 @@ def _rmsnorm_pallas(x, scale, *, eps, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=out_struct((rp, D), x.dtype, x2, scale),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale)
     return out[:rows].reshape(orig_shape)

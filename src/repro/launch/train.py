@@ -15,6 +15,7 @@ sync strategy mid-run — see DESIGN.md §5/§9.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import os
 import time
@@ -23,6 +24,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import CheckpointManager
 from repro.config import (MembershipConfig, ModelConfig, OuterCommConfig,
@@ -116,6 +118,27 @@ def resolve_auto_sync_delay(tc: TrainConfig, mc: ModelConfig,
     return ModelDelayController(tc, mc, pc, chip=chip).initial_delay()
 
 
+_GC_SPANS = []  # the python.gc span of the collection under way
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``python.gc`` span per collector pass."""
+    if phase == "start":
+        span = TraceAnnotation("python.gc", generation=info["generation"])
+        span.__enter__()
+        _GC_SPANS.append(span)
+    elif _GC_SPANS:
+        _GC_SPANS.pop().__exit__(None, None, None)
+
+
+def trace_gc_passes() -> None:
+    """Show every garbage-collector pass as a ``python.gc`` span in a
+    profiler trace (a host pause the device may wait on); installed once
+    per process."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
+
+
 class Trainer:
     """Host-side training loop weaving inner/outer steps per the schedule.
 
@@ -168,6 +191,7 @@ class Trainer:
             tc = tc.replace(sync_delay=dec.clamped_delay(tc.sync_interval))
         self.mc, self.tc, self.pc = mc, tc, pc
         self.mesh = mesh
+        trace_gc_passes()
         self.sched = PierSchedule(tc)
         # jitted step bundles are cached per strategy: a controller that
         # switches back to an earlier rung re-uses the compiled steps
@@ -231,27 +255,46 @@ class Trainer:
         next ``sync_delay`` inner steps; apply then installs the result —
         the target with the stale-delta correction for outer events, the
         pending outer state for warmup accumulates.
+
+        Under a running profiler the step records three host spans, each
+        with the step number: ``trainer.dispatch`` until the inner or
+        warmup step is enqueued, ``trainer.outer`` around the step's outer
+        events (only on a step that has any), and ``trainer.metrics_read``
+        where the host waits for the step's metrics.
         """
-        sched, tc = self.sched, self.tc
         step = self.step
-        phase = sched.phase(step)
-        step_arr = jnp.asarray(step, jnp.int32)
-        t0 = time.perf_counter()
-        if phase == "warmup":
-            self.state, metrics = self.bundle.warmup_step(
-                self.state, batch, step_arr)
-        else:
-            self.state, metrics = self.bundle.inner_step(
-                self.state, batch, step_arr)
+        with TraceAnnotation("trainer.dispatch", step=step):
+            phase = self.sched.phase(step)
+            step_arr = jnp.asarray(step, jnp.int32)
+            t0 = time.perf_counter()
+            if phase == "warmup":
+                self.state, metrics = self.bundle.warmup_step(
+                    self.state, batch, step_arr)
+            else:
+                self.state, metrics = self.bundle.inner_step(
+                    self.state, batch, step_arr)
         ctrl = self.sync_controller
         if ctrl is not None and ctrl.wants_measurement:
             # materializing the metrics blocks on the inner step — the
             # wall time is the measured t_inner fed to the controller.
             # Outside the measurement windows the conversion stays at
             # return, off the dispatch-enqueue critical path.
-            metrics = {k: float(v) for k, v in metrics.items()}
+            with TraceAnnotation("trainer.metrics_read", step=step):
+                metrics = {k: float(v) for k, v in metrics.items()}
             ctrl.observe_step(time.perf_counter() - t0)
-        events = sched.events(step)
+        events = self.sched.events(step)
+        if events or self._inflight is not None:
+            with TraceAnnotation("trainer.outer", step=step):
+                self._outer_events(step, events)
+        self.step += 1
+        with TraceAnnotation("trainer.metrics_read", step=step):
+            return {k: float(v) for k, v in metrics.items()}
+
+    def _outer_events(self, step: int, events):
+        """The outer events of ``step``: dispatches, applies, the fused
+        eager outer step, and the controller round that follows them."""
+        sched = self.sched
+        ctrl = self.sync_controller
         chunked = self.bundle.chunk_dispatch_steps is not None
         # while the controller still wants t_comm samples the sync must go
         # through dispatch/apply (bit-identical at d=0); once measurement
@@ -305,8 +348,6 @@ class Trainer:
             # length — never let a due apply slip past its step
             if self._inflight is not None and self._inflight[0] <= step:
                 self._apply_inflight()
-        self.step += 1
-        return {k: float(v) for k, v in metrics.items()}
 
     def _dispatch_accumulate(self, ev):
         """Warmup accumulate as a dispatch/apply pair (DESIGN.md §9).

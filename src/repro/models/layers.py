@@ -151,6 +151,7 @@ def embed_tokens(p, tokens, cfg: ModelConfig, *, position_offset=0):
     return x
 
 
+@jax.named_scope("pier.head")
 def lm_logits(p, x, cfg: ModelConfig):
     """x: (B, S, D) -> (B, S, V); fp32 logits for a stable loss."""
     table = p["tokens"].T if cfg.tie_embeddings else p["lm_head"]

@@ -43,7 +43,23 @@ def loss_fn(
     """
     logits, aux = forward(
         params, cfg, batch, use_pallas=use_pallas, remat=remat)
-    labels = batch["labels"]
+    loss, mask = _token_nll(logits, batch["labels"])
+    total = loss
+    if cfg.is_moe:
+        total = total + cfg.router_aux_loss_coef * aux["moe_aux"]
+        total = total + 1e-4 * aux["moe_z"]
+    metrics = {
+        "lm_loss": loss,
+        "moe_aux": aux["moe_aux"],
+        "moe_z": aux["moe_z"],
+        "tokens": jnp.sum(mask),
+    }
+    return total, metrics
+
+
+@jax.named_scope("pier.head")
+def _token_nll(logits, labels):
+    """(mean negative log-likelihood over the unmasked positions, the mask)."""
     mask = (labels >= 0).astype(jnp.float32)
     labels_safe = jnp.maximum(labels, 0)
     logz = jax.nn.logsumexp(logits, axis=-1)
@@ -57,18 +73,7 @@ def loss_fn(
                    axis=-1)
     nll = (logz - gold) * mask
     denom = jnp.maximum(jnp.sum(mask), 1.0)
-    loss = jnp.sum(nll) / denom
-    total = loss
-    if cfg.is_moe:
-        total = total + cfg.router_aux_loss_coef * aux["moe_aux"]
-        total = total + 1e-4 * aux["moe_z"]
-    metrics = {
-        "lm_loss": loss,
-        "moe_aux": aux["moe_aux"],
-        "moe_z": aux["moe_z"],
-        "tokens": jnp.sum(mask),
-    }
-    return total, metrics
+    return jnp.sum(nll) / denom, mask
 
 
 # ---------------------------------------------------------------------------

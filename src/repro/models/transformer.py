@@ -250,6 +250,15 @@ def forward(
 
     Returns (logits, aux) where aux = {"moe_aux", "moe_z", "kv" (if collected)}.
     """
+    x, aux_out = _blocks(params, cfg, batch, use_pallas=use_pallas,
+                         remat=remat, collect_kv=collect_kv)
+    return L.lm_logits(params["embed"], x, cfg), aux_out
+
+
+@jax.named_scope("pier.blocks")
+def _blocks(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
+            use_pallas: bool, remat: str, collect_kv: bool):
+    """The embedding, every decoder layer and the final norm: (x, aux)."""
     tokens = batch["tokens"]
     S = tokens.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
@@ -332,8 +341,7 @@ def forward(
                 kv_streams.append(extra)
 
     x = L.apply_norm(params["final_norm"], x, cfg)
-    logits = L.lm_logits(params["embed"], x, cfg)
     aux_out = {"moe_aux": moe_aux, "moe_z": moe_z}
     if collect_kv:
         aux_out["kv"] = kv_streams
-    return logits, aux_out
+    return x, aux_out

@@ -49,6 +49,13 @@ a mid-run strategy switch (DESIGN.md §9) builds a fresh bundle (the
 re-jit boundary; the Trainer caches bundles per strategy so switching
 back is compile-free) and retargets ``OuterState.residual`` through
 ``init_residual`` when the residual requirement changes.
+
+The steps' ops carry ``jax.named_scope`` scopes in their name stacks,
+which a profiler trace shows: ``pier.blocks`` and ``pier.head`` (the model,
+``jvp(...)`` forward and ``transpose(jvp(...))`` backward),
+``pier.optimizer`` (clip, AdamW, the metrics' means), and in the outer
+events ``pier.outer.delta`` (Δθ), ``pier.outer.exchange`` (the strategy's
+per-leaf reduce) and ``pier.outer.update`` (the rest of each body).
 """
 
 from __future__ import annotations
@@ -268,18 +275,23 @@ def build_train_steps(
                 grads, loss = grads_and_loss(params, batch, step)
                 if global_sync and manual:
                     grads = jax.lax.pmean(grads, manual)
-                grads, gnorm = clip_by_global_norm(grads, tc.clip_grad)
-                lr = lr_at(tc, step)
-                new_params, new_opt = adamw_update(grads, opt, params, tc, lr)
-                metrics = {
-                    "loss": jax.lax.pmean(loss, manual) if manual else loss,
-                    "grad_norm": (jax.lax.pmean(gnorm, manual)
-                                  if manual else gnorm),
-                    "lr": lr,
-                }
-                new_state = TrainState(
-                    params=jax.tree.map(lambda x: x[None], new_params),
-                    opt=jax.tree.map(lambda x: x[None], new_opt))
+                with jax.named_scope("pier.optimizer"):
+                    grads, gnorm = clip_by_global_norm(grads, tc.clip_grad)
+                    lr = lr_at(tc, step)
+                    new_params, new_opt = adamw_update(grads, opt, params,
+                                                       tc, lr)
+                    metrics = {
+                        "loss": (jax.lax.pmean(loss, manual) if manual
+                                 else loss),
+                        "grad_norm": (jax.lax.pmean(gnorm, manual)
+                                      if manual else gnorm),
+                        "lr": lr,
+                    }
+                    # in the scope: XLA roots the fused AdamW update at this
+                    # (G,)-stacking, and the fusion takes the root's scope
+                    new_state = TrainState(
+                        params=jax.tree.map(lambda x: x[None], new_params),
+                        opt=jax.tree.map(lambda x: x[None], new_opt))
                 return new_state, metrics
         return body
 
@@ -387,6 +399,7 @@ def build_train_steps(
             return tree
         return jax.lax.pmean(tree, manual)
 
+    @jax.named_scope("pier.outer.exchange")
     def _reduce_delta_leaf(d, r, ctx=reduce_ctx, spec=None):
         """One Δθ leaf -> (globally averaged payload, new residual | None).
 
@@ -400,6 +413,7 @@ def build_train_steps(
         """
         return strategy.reduce_leaf(d, r, tc, ctx.with_leaf_spec(spec))
 
+    @jax.named_scope("pier.outer.delta")
     def _reduced_delta(params, outer, ctx=reduce_ctx):
         """(delta_avg tree, new residual tree | None) for one group.
 
@@ -437,7 +451,7 @@ def build_train_steps(
         return {"residual": new_res} if compress else {}
 
     def accumulate_body(state, outer, mu):
-        with use_rules(rules):
+        with use_rules(rules), jax.named_scope("pier.outer.update"):
             params = jax.tree.map(lambda x: x[0], state.params)
             if manual:
                 # During warmup all groups hold identical params (they run
@@ -475,7 +489,7 @@ def build_train_steps(
                                        **_out_sh(outer_shardings))
 
     def outer_body(state, outer, mu, olr, coords):
-        with use_rules(rules):
+        with use_rules(rules), jax.named_scope("pier.outer.update"):
             params = jax.tree.map(lambda x: x[0], state.params)
             delta, new_res = _reduced_delta(
                 params, outer, _local_ctx(coords))  # THE collective
@@ -506,7 +520,7 @@ def build_train_steps(
     # concurrently with the next ``sync_delay`` inner steps. apply installs
     # the target with the stale-delta correction once the window closes.
     def dispatch_body(state, outer, mu, olr, coords):
-        with use_rules(rules):
+        with use_rules(rules), jax.named_scope("pier.outer.update"):
             params = jax.tree.map(lambda x: x[0], state.params)
             delta, new_res = _reduced_delta(
                 params, outer, _local_ctx(coords))  # THE collective
@@ -565,7 +579,7 @@ def build_train_steps(
 
         def make_chunk_dispatch(lo, hi):
             def chunk_body(state, outer, mu, olr, coords):
-                with use_rules(rules):
+                with use_rules(rules), jax.named_scope("pier.outer.update"):
                     ctx = _local_ctx(coords)
                     params = jax.tree.map(lambda x: x[0], state.params)
                     p_flat = ptreedef.flatten_up_to(params)
@@ -576,8 +590,9 @@ def build_train_steps(
                         if compress else [None] * len(p_flat))
                     payload, new_res, snaps = [], [], []
                     for j in range(lo, hi):
-                        d = (p_flat[j].astype(jnp.float32)
-                             - a_flat[j].astype(jnp.float32))
+                        with jax.named_scope("pier.outer.delta"):
+                            d = (p_flat[j].astype(jnp.float32)
+                                 - a_flat[j].astype(jnp.float32))
                         da, nr = _reduce_delta_leaf(d, r_flat[j], ctx,
                                                     pspec_flat[j])
                         payload.append(da)
@@ -618,7 +633,7 @@ def build_train_steps(
 
         def make_chunk_apply(lo, hi):
             def apply_chunk_body(state, chunk):
-                with use_rules(rules):
+                with use_rules(rules), jax.named_scope("pier.outer.update"):
                     params = jax.tree.map(lambda x: x[0], state.params)
                     p_flat = ptreedef.flatten_up_to(params)
                     span = tuple(p_flat[lo:hi])
@@ -692,7 +707,7 @@ def build_train_steps(
             return jax.jit(f, out_shardings=_res_shardings)(state)
 
     def apply_body(state, dispatch):
-        with use_rules(rules):
+        with use_rules(rules), jax.named_scope("pier.outer.update"):
             params = jax.tree.map(lambda x: x[0], state.params)
             snap = jax.tree.map(lambda x: x[0], dispatch.snapshot)
             new_params = outer_apply(dispatch.target, snap, params)
@@ -759,7 +774,7 @@ def build_train_steps(
 
         def elastic_outer_body(state, outer, mu, olr, coords, weights,
                                live):
-            with use_rules(rules):
+            with use_rules(rules), jax.named_scope("pier.outer.update"):
                 params = jax.tree.map(lambda x: x[0], state.params)
                 delta, new_res = _reduced_delta(
                     params, outer, _member_ctx(coords, weights))
@@ -788,7 +803,7 @@ def build_train_steps(
             **_out_sh((state_shardings, outer_shardings)))
 
         def elastic_dispatch_body(state, outer, mu, olr, coords, weights):
-            with use_rules(rules):
+            with use_rules(rules), jax.named_scope("pier.outer.update"):
                 params = jax.tree.map(lambda x: x[0], state.params)
                 delta, new_res = _reduced_delta(
                     params, outer, _member_ctx(coords, weights))
@@ -815,7 +830,7 @@ def build_train_steps(
             **_out_sh((dispatch_shardings, outer_shardings)))
 
         def elastic_apply_body(state, dispatch, coords, live):
-            with use_rules(rules):
+            with use_rules(rules), jax.named_scope("pier.outer.update"):
                 params = jax.tree.map(lambda x: x[0], state.params)
                 snap = jax.tree.map(lambda x: x[0], dispatch.snapshot)
                 applied = outer_apply(dispatch.target, snap, params)
