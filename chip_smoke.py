@@ -239,7 +239,7 @@ def train(label: str, mc, *, mesh_shape, compression="none",
             metrics = trainer.train_step(batch)
             jax.block_until_ready((trainer.state, trainer.outer))
             step_s.append(time.perf_counter() - t0)
-            losses.append(metrics["loss"])
+            losses.append(float(metrics["loss"]))
             phases.append("sync" if synced else phase)
             if groups > 1 and (synced or optimizer == "adamw"):
                 _groups_agree(trainer.state.params, groups)

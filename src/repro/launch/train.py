@@ -204,6 +204,12 @@ class Trainer:
                      if checkpoint_dir else None)
         self._outer_on_host = False
         self.history = []
+        # the host's run-ahead of one step: the metrics train_step
+        # returned last, which the next call waits on; the calls that
+        # waited, and those whose wait found that step still running
+        self._last_metrics = None
+        self.steps_waited = 0
+        self.steps_ahead = 0
         # the (single) in-flight window, uniform over ops (DESIGN.md §9):
         # (apply_at, "outer", DispatchState | [ChunkDispatch]) or
         # (apply_at, "accumulate", pending OuterState).
@@ -256,11 +262,20 @@ class Trainer:
         the target with the stale-delta correction for outer events, the
         pending outer state for warmup accumulates.
 
+        Returns this step's metrics (``loss``, ``grad_norm``, ``lr``) as
+        the device scalars the jitted step produced, not read to the host.
+        The host runs at most one step ahead of the device: after
+        enqueueing step n the call waits until step n-1's metrics are
+        ready, so the next batch's transfer and dispatch overlap step n's
+        compute, and a returned step n-1 is finished on the device.
+
         Under a running profiler the step records three host spans, each
         with the step number: ``trainer.dispatch`` until the inner or
         warmup step is enqueued, ``trainer.outer`` around the step's outer
         events (only on a step that has any), and ``trainer.metrics_read``
-        where the host waits for the step's metrics.
+        where the host waits for the previous step's metrics; its
+        ``ahead`` is 1 when that step was still running as the wait
+        began (counted in ``steps_ahead`` of ``steps_waited``).
         """
         step = self.step
         with TraceAnnotation("trainer.dispatch", step=step):
@@ -277,18 +292,25 @@ class Trainer:
         if ctrl is not None and ctrl.wants_measurement:
             # materializing the metrics blocks on the inner step — the
             # wall time is the measured t_inner fed to the controller.
-            # Outside the measurement windows the conversion stays at
-            # return, off the dispatch-enqueue critical path.
+            # Outside the measurement windows the host only waits for the
+            # previous step, off the dispatch-enqueue critical path.
             with TraceAnnotation("trainer.metrics_read", step=step):
-                metrics = {k: float(v) for k, v in metrics.items()}
+                jax.device_get(metrics)
             ctrl.observe_step(time.perf_counter() - t0)
         events = self.sched.events(step)
         if events or self._inflight is not None:
             with TraceAnnotation("trainer.outer", step=step):
                 self._outer_events(step, events)
         self.step += 1
-        with TraceAnnotation("trainer.metrics_read", step=step):
-            return {k: float(v) for k, v in metrics.items()}
+        prev, self._last_metrics = self._last_metrics, metrics
+        if prev is not None:
+            ahead = not all(v.is_ready() for v in prev.values())
+            with TraceAnnotation("trainer.metrics_read", step=step,
+                                 ahead=int(ahead)):
+                jax.block_until_ready(prev)
+            self.steps_waited += 1
+            self.steps_ahead += ahead
+        return metrics
 
     def _outer_events(self, step: int, events):
         """The outer events of ``step``: dispatches, applies, the fused
@@ -574,19 +596,28 @@ class Trainer:
 
     def run(self, steps: int, pipeline, *, log_every: int = 10,
             ckpt_every: int = 0):
+        """Train ``steps`` steps from ``pipeline``; returns ``history``,
+        every step's metrics so far as Python floats.
+
+        The loop keeps ``train_step``'s run-ahead of one step: it reads
+        metrics to the host only at the ``log_every`` steps, and all of
+        the history in one transfer at the end.
+        """
         t0 = time.time()
         for _ in range(steps):
             batch = next(pipeline)
             metrics = self.train_step(batch)
             self.history.append(metrics)
             if log_every and self.step % log_every == 0:
+                m = jax.device_get(metrics)
                 dt = (time.time() - t0) / max(self.step, 1)
-                print(f"step {self.step:6d} loss {metrics['loss']:.4f} "
-                      f"lr {metrics['lr']:.2e} gnorm {metrics['grad_norm']:.3f} "
+                print(f"step {self.step:6d} loss {m['loss']:.4f} "
+                      f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.3f} "
                       f"({dt*1e3:.0f} ms/step avg)", flush=True)
             if ckpt_every and self.ckpt and self.step % ckpt_every == 0:
                 self.save()
         self.flush()
+        self.history = jax.tree.map(float, jax.device_get(self.history))
         return self.history
 
     def save(self):

@@ -91,7 +91,8 @@ def test_outer_step_scopes(outer_ops, scope):
 
 @pytest.fixture(scope="module")
 def host_spans(trainer, tmp_path_factory):
-    """Names of the host spans of two train steps across an outer sync."""
+    """The host spans of two train steps across an outer sync: each
+    name with the names of its arguments."""
     from jax.profiler import ProfileData
 
     tr, pipe = trainer
@@ -103,9 +104,18 @@ def host_spans(trainer, tmp_path_factory):
             tr.train_step(next(pipe))
         gc.collect()
     (xplane,) = out.glob("plugins/profile/*/*.xplane.pb")
-    pd = ProfileData.from_file(str(xplane))
-    return {e.name for plane in pd.planes if plane.name.startswith("/host:")
-            for line in plane.lines for e in line.events}
+    spans = {}
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    spans.setdefault(e.name, set()).update(
+                        name for name, _ in e.stats)
+    return spans
+
+
+# The arguments a span carries beyond its name, where a reader needs them.
+SPAN_ARGS = {"trainer.metrics_read": {"step", "ahead"}}
 
 
 @pytest.mark.parametrize("span", [
@@ -113,6 +123,7 @@ def host_spans(trainer, tmp_path_factory):
     "pipeline.queue_wait", "pipeline.device_put", "python.gc"])
 def test_host_spans(host_spans, span):
     assert span in host_spans
+    assert SPAN_ARGS.get(span, set()) <= host_spans[span]
 
 
 def _pallas_calls():
